@@ -209,11 +209,10 @@ def _dist_subprocess_main(d: int, run_baseline: bool) -> None:
     os.environ["JAX_DEFAULT_DTYPE_BITS"] = "32"
     import numpy as np
 
-    import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh
 
-    from repro.core.distributed import make_tiled_federated_solve
+    from repro.core.distributed import (federation_mesh,
+                                        make_tiled_federated_solve)
     from repro.launch.hlo_analysis import peak_aval_bytes
 
     n, c = 8, 16
@@ -232,7 +231,7 @@ def _dist_subprocess_main(d: int, run_baseline: bool) -> None:
         tiles.append(t)
     gt = jnp.asarray(np.stack(tiles))
     mt = jnp.asarray(np.stack([q[i * r:(i + 1) * r] for i in range(n)]))
-    mesh = Mesh(np.array(jax.devices()), ("data",))
+    mesh = federation_mesh(n)
 
     fn_dist = make_tiled_federated_solve(
         mesh, target_gamma=0.5, distributed_factor=True, dim=d)
@@ -288,23 +287,32 @@ def _dist_subprocess_main(d: int, run_baseline: bool) -> None:
     print(json.dumps(row))
 
 
-def bench_distributed_factor(d: int):
-    """Run one distributed-factor measurement in a fresh 8-device x64 child
-    (both knobs are process-global); the gather-then-factor baseline runs
-    only where its (d, d) per-device transient fits the budget."""
-    run_baseline = d * d * 8 <= DEVICE_TRANSIENT_BUDGET
-    env = dict(os.environ)
+def _cpu_child(*args: str) -> dict:
+    """Run one measurement of this module in a fresh child pinned to the
+    CPU (8 virtual devices, x64 — process-global knobs), so a parent that
+    holds a chip never waits on a child that wants it. Its row is labelled
+    ``platform="cpu"``: these are CPU walls, not device numbers."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # child needs repro (src) AND the benchmarks package (root) on its path
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root] +
         ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     res = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), _DIST_SUBPROC_FLAG,
-         str(d), str(int(run_baseline))],
+        [sys.executable, os.path.abspath(__file__), *args],
         capture_output=True, text=True, env=env, cwd=root)
     if res.returncode != 0:
-        raise RuntimeError(f"dist subprocess failed:\n{res.stderr}")
-    return json.loads(res.stdout.strip().splitlines()[-1])
+        raise RuntimeError(f"{args[0]} child failed:\n{res.stderr}")
+    return dict(json.loads(res.stdout.strip().splitlines()[-1]),
+                platform="cpu")
+
+
+def bench_distributed_factor(d: int):
+    """One distributed-factor measurement in a CPU child; the
+    gather-then-factor baseline runs only where its (d, d) per-device
+    transient fits the budget."""
+    run_baseline = d * d * 8 <= DEVICE_TRANSIENT_BUDGET
+    return _cpu_child(_DIST_SUBPROC_FLAG, str(d), str(int(run_baseline)))
 
 
 def _tiled_subprocess_main(d: int) -> None:
@@ -358,19 +366,7 @@ def _tiled_subprocess_main(d: int) -> None:
 
 
 def bench_tiled(d: int):
-    env = dict(os.environ)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    # child needs repro (src) AND the benchmarks package (root) on its path
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root] +
-        ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    res = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), _TILED_SUBPROC_FLAG,
-         str(d)],
-        capture_output=True, text=True, env=env, cwd=root)
-    if res.returncode != 0:
-        raise RuntimeError(f"tiled subprocess failed:\n{res.stderr}")
-    return json.loads(res.stdout.strip().splitlines()[-1])
+    return _cpu_child(_TILED_SUBPROC_FLAG, str(d))
 
 
 def run(quick: bool = False) -> list[dict]:
@@ -413,7 +409,7 @@ def run(quick: bool = False) -> list[dict]:
     row = bench_tiled(d4)
     out.append(row)
     print_table(
-        "Tiled-Gram ShardedCoordinator, 8-way mesh, x64 subprocess",
+        "Tiled-Gram ShardedCoordinator, 8-way CPU mesh, x64 subprocess",
         ["case", "sync s", "tiled s", "max |Δ| vs sync", "tile MB/shard",
          "leaf MB/shard"],
         [[f"d={d4}", f"{row['sync_solve_s']:.2f}",
@@ -430,7 +426,7 @@ def run(quick: bool = False) -> list[dict]:
     out.extend(dist_rows)
     print_table(
         "Tile-parallel distributed factor vs gather-then-factor, 8-way "
-        "mesh, x64 subprocess per d",
+        "CPU mesh, x64 subprocess per d",
         ["d", "dist s", "gather s", "speedup", "peak MB dist",
          "peak MB gather", "budget MB", "rel err"],
         [[r["d"], f"{r['dist_s']:.2f}",

@@ -21,12 +21,12 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.engine import AnalyticEngine, SuffStats
 from repro.core.streaming import AnalyticState, to_stats
+from repro.kernels.ops import interpret_default
+from repro.launch.mesh import auto_mesh
 
 __all__ = [
     "psum_stats",
@@ -61,7 +61,7 @@ def federation_mesh(n_shards: int, axis_names: Sequence[str] = ("data",),
     if len(tuple(axis_names)) != 1:
         raise ValueError(
             f"federation_mesh builds 1-axis meshes, got {tuple(axis_names)}")
-    return Mesh(np.array(devices[:n]), tuple(axis_names))
+    return auto_mesh((n,), axis_names, devices=devices[:n])
 
 
 def psum_stats(stats: SuffStats, axis_names: Sequence[str]) -> SuffStats:
@@ -142,7 +142,7 @@ def make_federated_solve(
     solver = federated_solve if use_ri else federated_solve_no_ri
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(in_spec,), out_specs=P()
+        jax.shard_map, mesh=mesh, in_specs=(in_spec,), out_specs=P()
     )
     def _agg(stacked: AnalyticState) -> jax.Array:
         local = jax.tree.map(lambda x: jnp.sum(x, axis=0), stacked)
@@ -210,7 +210,7 @@ def make_tiled_federated_solve(
     n_shards = 1
     for a in ax:
         n_shards *= mesh.shape[a]
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_default()
 
     if distributed_factor:
         from repro.kernels.solve import (
@@ -218,8 +218,8 @@ def make_tiled_federated_solve(
             tile_cholesky_factor, tile_cholesky_solve)
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(),
-            check_rep=False,   # gathers + dynamic slices defeat rep inference
+            jax.shard_map, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P(),
+            check_vma=False,   # gathers + dynamic slices defeat vma inference
         )
         def _agg_dist(gram_tiles: jax.Array,
                       moment_tiles: jax.Array) -> jax.Array:
@@ -256,7 +256,7 @@ def make_tiled_federated_solve(
         return jax.jit(_agg_dist)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P()
+        jax.shard_map, mesh=mesh, in_specs=(P(ax), P(ax)), out_specs=P()
     )
     def _agg(gram_tiles: jax.Array, moment_tiles: jax.Array) -> jax.Array:
         # linear shard index over the (possibly multi-axis) federation mesh

@@ -331,8 +331,9 @@ class JaxBackend:
             g = g.astype(self.dtype)
             q = q.astype(self.dtype)
         else:
-            g = x.T @ x
-            q = x.T @ y
+            # full-f32 MXU passes: a TPU's default for f32 is one bf16 pass
+            g = jnp.dot(x.T, x, precision="highest")
+            q = jnp.dot(x.T, y, precision="highest")
         return g, q, jnp.asarray(x.shape[0], self.dtype)
 
     def factor(self, a) -> Factorization:

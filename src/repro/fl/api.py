@@ -951,7 +951,7 @@ class ShardedCoordinator:
             raise BadRequest(f"num_shards must be ≥1, got {num_shards}")
         if mesh is None:
             if num_shards is None:
-                mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+                mesh = self._make_mesh(len(jax.devices()), ("data",))
             else:
                 # tiled mode keeps one row tile per device, so the mesh IS
                 # the shard count; non-tiled shards are host accumulators —

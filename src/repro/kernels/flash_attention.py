@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
@@ -156,7 +155,7 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -36,10 +36,9 @@ Precision variants (the ``precision`` argument):
 
 On TPU the calls compile through Mosaic with the whole batched system
 resident in VMEM — which bounds native occupancy to roughly d ≤ 1024 at f32
-per core (d² · batch · 4 bytes against ~16 MB); past that, run one system
-per grid step or shard the γ-grid across cores. HBM-tiled panels (the
-``gram.py`` treatment) are the open next rung for d=6144 *single-system*
-factorization; the serving path at that scale instead shards the Gram
+per core (the kernels raise the scoped VMEM limit and step one system at a
+time at that width). Wider single systems stream panels through VMEM
+(:func:`streamed_cholesky`); the serving path at d=6144 shards the Gram
 itself (``repro.fl.api.ShardedCoordinator(tiled_gram=True)``). Off-TPU the
 kernels execute in interpret mode (``repro.kernels.ops`` defaults) — which
 is how this repo's CI exercises them, and fast enough to beat the host
@@ -58,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "blocked_cholesky",
@@ -102,13 +102,26 @@ def _split(a):
     return hi, (a - hi)
 
 
-def _make_mm(precision: str):
-    """Batched tile matmul ``(b, n, k) @ (b, k, m)`` at the requested
-    precision: native dtype, or the 3-product emulated-f64 split."""
-    dims = (((2,), (1,)), ((0,), (0,)))
+# dot_general dimension numbers: batched (b, ·, ·) tiles and plain 2-D tiles
+_BDIMS_NN = (((2,), (1,)), ((0,), (0,)))   # a @ b
+_BDIMS_NT = (((2,), (2,)), ((0,), (0,)))   # a @ bᵀ
+_BDIMS_TN = (((1,), (1,)), ((0,), (0,)))   # aᵀ @ b
+_DIMS_NN = (((1,), (0,)), ((), ()))       # a @ b
+_DIMS_NT = (((1,), (1,)), ((), ()))       # a @ bᵀ
+_DIMS_TN = (((0,), (0,)), ((), ()))       # aᵀ @ b
+
+
+def _make_mm(precision: str, dims):
+    """Tile matmul with dimension numbers ``dims`` at the requested
+    precision: native dtype, or the 3-product emulated-f64 split.
+
+    Every product asks for the MXU's full-f32 passes: a TPU's default for
+    f32 operands is one bf16 pass, which left the d=1024 solves ~5e-3 off
+    the f64 reference on a v5e (``chip_smoke.py``)."""
 
     def mm(a, b):
-        return lax.dot_general(a, b, dims, preferred_element_type=a.dtype)
+        return lax.dot_general(a, b, dims, precision=lax.Precision.HIGHEST,
+                               preferred_element_type=a.dtype)
 
     if precision != "f32_x2":
         return mm
@@ -116,110 +129,129 @@ def _make_mm(precision: str):
     def mm_x2(a, b):
         ah, al = _split(a)
         bh, bl = _split(b)
-        hi = lax.dot_general(ah, bh, dims, preferred_element_type=a.dtype)
-        mid = (lax.dot_general(ah, bl, dims, preferred_element_type=a.dtype)
-               + lax.dot_general(al, bh, dims,
-                                 preferred_element_type=a.dtype))
-        return hi + mid
+        return mm(ah, bh) + (mm(ah, bl) + mm(al, bh))
 
     return mm_x2
 
 
+# Mosaic lowers no value indexed by a traced loop counter (``s[:, j, j]``,
+# ``s.at[:, :, j].set``), so the column sweeps below pick and place rows and
+# columns with iota masks: a masked reduction reads one, a select writes one.
+# Each is O(m²) VPU work per step on a tile the step touches in full anyway.
+
+
+def _iota(shape, dim):
+    return lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _pick_col(a, j):
+    """``a[..., :, j]`` as a ``(..., m, 1)`` column."""
+    hit = _iota(a.shape, a.ndim - 1) == j
+    return jnp.sum(jnp.where(hit, a, jnp.zeros_like(a)), axis=-1,
+                   keepdims=True)
+
+
+def _pick_row(a, i):
+    """``a[..., i, :]`` as a ``(..., 1, n)`` row."""
+    hit = _iota(a.shape, a.ndim - 2) == i
+    return jnp.sum(jnp.where(hit, a, jnp.zeros_like(a)), axis=-2,
+                   keepdims=True)
+
+
 def _factor_tile(tile):
-    """Unblocked Cholesky of a batch of SPD tiles ``(b, m, m)`` → lower L.
+    """Unblocked Cholesky of SPD tiles ``(..., m, m)`` → lower L.
 
     A ``fori_loop`` column sweep with masked full-width updates, so every
     iteration has static shapes (VPU work on one tile, batched); the upper
-    triangle is written as zeros. A non-PD tile yields NaNs (sqrt of a
-    non-positive pivot) that propagate to the caller's fallback check.
+    triangle is written as zeros. The trailing block stays symmetric through
+    the sweep, so row j past the pivot is column j transposed. A non-PD tile
+    yields NaNs (sqrt of a non-positive pivot) that propagate to the
+    caller's fallback check.
     """
-    m = tile.shape[-1]
-    rows = jnp.arange(m)
+    rows = _iota(tile.shape[:-1] + (1,), tile.ndim - 2)
+    cols = _iota(tile.shape[:-2] + (1, tile.shape[-1]), tile.ndim - 1)
+    lanes = _iota(tile.shape, tile.ndim - 1)
 
     def body(j, s):
-        pv = jnp.sqrt(s[:, j, j])
-        col = s[:, :, j] / pv[:, None]
-        below = rows[None, :] > j
-        colm = jnp.where(below, col, jnp.zeros_like(col))
-        s = s - colm[:, :, None] * colm[:, None, :]
-        cj = jnp.where(rows[None, :] == j, pv[:, None], colm)
-        return s.at[:, :, j].set(cj)
+        col = _pick_col(s, j)                        # (..., m, 1)
+        pv = jnp.sqrt(_pick_row(col, j))             # (..., 1, 1)
+        colm = jnp.where(rows > j, col / pv, jnp.zeros_like(col))
+        rowm = jnp.where(cols > j, _pick_row(s, j) / pv,
+                         jnp.zeros_like(pv))         # (..., 1, m)
+        s = s - colm * rowm
+        cj = jnp.where(rows == j, pv, colm)
+        return jnp.where(lanes == j, cj, s)
 
-    return lax.fori_loop(0, m, body, tile)
+    return lax.fori_loop(0, tile.shape[-1], body, tile)
 
 
 def _tri_inv_tile(l):
-    """Inverse of a batch of lower-triangular tiles ``(b, m, m)`` by forward
-    substitution on the identity — turns panel trsm into one matmul."""
-    m = l.shape[-1]
-    rows = jnp.arange(m)
-    eye = jnp.eye(m, dtype=l.dtype)
+    """Inverse of lower-triangular tiles ``(..., m, m)`` by column-oriented
+    forward substitution on the identity — turns panel trsm into one
+    matmul."""
+    rows = _iota(l.shape[:-1] + (1,), l.ndim - 2)
+    sub = _iota(l.shape, l.ndim - 2)
 
-    def body(i, z):
-        li = l[:, i, :]
-        strict = jnp.where(rows[None, :] < i, li, jnp.zeros_like(li))
-        acc = lax.dot_general(strict, z, (((1,), (1,)), ((0,), (0,))),
-                              preferred_element_type=l.dtype)
-        zi = (eye[i][None, :] - acc) / l[:, i, i][:, None]
-        return z.at[:, i, :].set(zi)
+    def body(j, z):
+        lcol = _pick_col(l, j)                       # (..., m, 1)
+        zj = _pick_row(z, j) / _pick_row(lcol, j)    # (..., 1, m)
+        below = jnp.where(rows > j, lcol, jnp.zeros_like(lcol))
+        return jnp.where(sub == j, zj, z - below * zj)
 
-    return lax.fori_loop(0, m, body, jnp.zeros_like(l))
-
-
-def _t(a):
-    return jnp.swapaxes(a, -1, -2)
+    eye = (_iota(l.shape, l.ndim - 2) == _iota(l.shape, l.ndim - 1))
+    return lax.fori_loop(0, l.shape[-1], body, eye.astype(l.dtype))
 
 
-def _factor_panels(a, block, mm):
-    """Right-looking blocked Cholesky on a batch ``(b, d, d)``; panels are
-    unrolled at trace time (static tile shapes, true d³/3 flops). Returns the
-    lower factor and the per-panel inverse diagonal blocks (reused by the
-    solve phase so substitution needs no extra column sweeps)."""
-    d = a.shape[-1]
-    if block >= d:
-        # single panel: no trailing updates (a whole-array .at[].set would
-        # also lower to a scatter Pallas refuses to capture)
-        l = _factor_tile(a)
-        return l, [_tri_inv_tile(l)]
+def _factor_panels(s_ref, block, precision):
+    """Right-looking blocked Cholesky, in place on a ``(b, d, d)`` VMEM ref.
+
+    Panels are unrolled at trace time (static, tile-aligned slices; true
+    d³/3 flops). Leaves the clean lower factor in ``s_ref`` and returns the
+    per-panel inverse diagonal blocks (reused by the solve phase so
+    substitution needs no extra column sweeps)."""
+    d = s_ref.shape[-1]
+    mm_nt = _make_mm(precision, _BDIMS_NT)
     inv_blocks = []
     for o in range(0, d, block):
-        l11 = _factor_tile(a[:, o:o + block, o:o + block])
+        e = o + block
+        l11 = _factor_tile(s_ref[:, o:e, o:e])
         zinv = _tri_inv_tile(l11)
         inv_blocks.append(zinv)
-        a = a.at[:, o:o + block, o:o + block].set(l11)
-        if o + block < d:
-            l21 = mm(a[:, o + block:, o:o + block], _t(zinv))
-            a = a.at[:, o + block:, o:o + block].set(l21)
-            a = a.at[:, o + block:, o + block:].add(-mm(l21, _t(l21)))
+        s_ref[:, o:e, o:e] = l11
+        if e < d:
+            l21 = mm_nt(s_ref[:, e:, o:e], zinv)
+            s_ref[:, e:, o:e] = l21
+            s_ref[:, e:, e:] = s_ref[:, e:, e:] - mm_nt(l21, l21)
     # zero the (garbage) strict upper triangle so the output is a clean L
-    d_idx = jnp.arange(d)
-    lower = d_idx[:, None] >= d_idx[None, :]
-    return jnp.where(lower[None], a, jnp.zeros_like(a)), inv_blocks
+    shape = s_ref.shape
+    lower = _iota(shape, 1) >= _iota(shape, 2)
+    s_ref[...] = jnp.where(lower, s_ref[...], jnp.zeros(shape, s_ref.dtype))
+    return inv_blocks
 
 
-def _solve_panels(l, b, block, mm, inv_blocks=None):
-    """Batched ``L Lᵀ x = b`` by blocked forward + backward substitution."""
-    d = l.shape[-1]
-    if inv_blocks is None:
-        inv_blocks = [_tri_inv_tile(l[:, o:o + block, o:o + block])
-                      for o in range(0, d, block)]
-    if block >= d:
-        inv = inv_blocks[0]
-        return mm(_t(inv), mm(inv, b))
-    panels = list(enumerate(range(0, d, block)))
-    y = jnp.zeros_like(b)
-    for k, o in panels:
-        rhs = b[:, o:o + block]
-        if o:
-            rhs = rhs - mm(l[:, o:o + block, :o], y[:, :o])
-        y = y.at[:, o:o + block].set(mm(inv_blocks[k], rhs))
-    x = jnp.zeros_like(b)
-    for k, o in reversed(panels):
-        rhs = y[:, o:o + block]
-        if o + block < d:
-            rhs = rhs - mm(_t(l[:, o + block:, o:o + block]), x[:, o + block:])
-        x = x.at[:, o:o + block].set(mm(_t(inv_blocks[k]), rhs))
-    return x
+def _solve_panels(l, b, block, precision, inv_blocks):
+    """Batched ``L Lᵀ x = b`` by blocked forward + backward substitution.
+
+    ``l`` (b, d, d) and ``b`` (b, d, c) are refs or values (only static
+    slices are read). Returns the solution as a list of (b, block, c)
+    panels, so the caller writes each one into its output ref."""
+    mm_nn = _make_mm(precision, _BDIMS_NN)
+    mm_tn = _make_mm(precision, _BDIMS_TN)
+    n = l.shape[-1] // block
+    sl = [slice(k * block, (k + 1) * block) for k in range(n)]
+    ys = []
+    for k in range(n):
+        rhs = b[:, sl[k]]
+        for j in range(k):
+            rhs = rhs - mm_nn(l[:, sl[k], sl[j]], ys[j])
+        ys.append(mm_nn(inv_blocks[k], rhs))
+    xs = [None] * n
+    for k in reversed(range(n)):
+        rhs = ys[k]
+        for j in range(k + 1, n):
+            rhs = rhs - mm_tn(l[:, sl[j], sl[k]], xs[j])
+        xs[k] = mm_tn(inv_blocks[k], rhs)
+    return xs
 
 
 def _ceil_mult(v: int, m: int) -> int:
@@ -246,6 +278,22 @@ def _pad_spd(a, d_p):
 # pallas_call entry points
 # ---------------------------------------------------------------------------
 
+# A v5e core has 128 MiB of VMEM, but Mosaic's default scoped limit is
+# 16 MiB — less than one whole-resident d=1024 f32 system with its input and
+# output double-buffered. The whole-resident kernels raise the limit, and
+# size their per-step system batch so its bytes stay under _STEP_BYTES.
+_VMEM_LIMIT = 100 * 2**20
+_STEP_BYTES = 4 * 2**20
+
+
+def _whole_resident_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _systems_per_step(cap: int, count: int, d_p: int, dtype) -> int:
+    per_system = d_p * d_p * jnp.dtype(dtype).itemsize
+    return max(1, min(cap, count, _STEP_BYTES // per_system))
+
 
 @functools.partial(jax.jit,
                    static_argnames=("block", "precision", "interpret",
@@ -255,17 +303,17 @@ def blocked_cholesky(a: jax.Array, *, block: int = DEFAULT_BLOCK,
                      batch_block: int = DEFAULT_BATCH_BLOCK) -> jax.Array:
     """Batched lower-Cholesky ``a (m, d, d) SPD → L`` via the blocked kernel.
 
-    The grid walks batch blocks; each step factors ``batch_block`` systems
-    together (one trace of the unrolled panel pipeline serves the whole
-    batch). Returns clean lower factors; non-PD inputs yield NaNs.
+    The grid walks batch blocks; each step factors up to ``batch_block``
+    systems together (one trace of the unrolled panel pipeline serves the
+    whole batch; wide systems go one per step to fit VMEM). Returns clean
+    lower factors; non-PD inputs yield NaNs.
     """
     m, d, _ = a.shape
     if m == 0:
         return jnp.zeros((0, d, d), a.dtype)
-    mm = _make_mm(precision)
     bs = min(block, _ceil_mult(d, 8))
     d_p = _ceil_mult(d, bs)
-    bb = min(batch_block, m)
+    bb = _systems_per_step(batch_block, m, d_p, a.dtype)
     m_p = _ceil_mult(m, bb)
     a = _pad_spd(a, d_p)
     if m_p != m:
@@ -275,8 +323,8 @@ def blocked_cholesky(a: jax.Array, *, block: int = DEFAULT_BLOCK,
         a = jnp.concatenate([a, pad], 0)
 
     def kernel(a_ref, l_ref):
-        l, _ = _factor_panels(a_ref[...], bs, mm)
-        l_ref[...] = l
+        l_ref[...] = a_ref[...]
+        _factor_panels(l_ref, bs, precision)
 
     out = pl.pallas_call(
         kernel,
@@ -284,6 +332,7 @@ def blocked_cholesky(a: jax.Array, *, block: int = DEFAULT_BLOCK,
         in_specs=[pl.BlockSpec((bb, d_p, d_p), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((bb, d_p, d_p), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_p, d_p, d_p), a.dtype),
+        compiler_params=_whole_resident_params(),
         interpret=interpret,
     )(a)
     return out[:m, :d, :d]
@@ -306,11 +355,10 @@ def cholesky_solve(l: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK,
     c = b.shape[-1]
     if m == 0:
         return jnp.zeros((0, d, c), b.dtype)
-    mm = _make_mm(precision)
     bs = min(block, _ceil_mult(d, 8))
     d_p = _ceil_mult(d, bs)
     c_p = _ceil_mult(c, 8)
-    bb = min(batch_block, m)
+    bb = _systems_per_step(batch_block, m, d_p, l.dtype)
     m_p = _ceil_mult(m, bb)
     if d_p != d:
         l = _pad_spd(l, d_p)       # identity tail: triangular and invertible
@@ -324,7 +372,11 @@ def cholesky_solve(l: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK,
             [b, jnp.zeros((m_p - m, d_p, c_p), b.dtype)], 0)
 
     def kernel(l_ref, b_ref, x_ref):
-        x_ref[...] = _solve_panels(l_ref[...], b_ref[...], bs, mm)
+        inv_blocks = [_tri_inv_tile(l_ref[:, o:o + bs, o:o + bs])
+                      for o in range(0, d_p, bs)]
+        xs = _solve_panels(l_ref, b_ref, bs, precision, inv_blocks)
+        for k, xk in enumerate(xs):
+            x_ref[:, k * bs:(k + 1) * bs] = xk
 
     out = pl.pallas_call(
         kernel,
@@ -335,6 +387,7 @@ def cholesky_solve(l: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK,
         ],
         out_specs=pl.BlockSpec((bb, d_p, c_p), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_p, d_p, c_p), b.dtype),
+        compiler_params=_whole_resident_params(),
         interpret=interpret,
     )(l, b)
     return out[:m, :d, :c]
@@ -350,24 +403,24 @@ def multi_gamma_solve(c: jax.Array, q: jax.Array, gammas: jax.Array, *,
                       interpret: bool = False) -> jax.Array:
     """The fused γ-sweep: solve ``(C + γ_j I) W_j = Q`` for a whole γ grid.
 
-    One ``pallas_call`` whose grid walks γ-blocks: each step broadcasts C
-    once, shifts the diagonal by its block of γs, factors all of them as one
-    batched blocked Cholesky, and runs the batched substitution — replacing
-    the per-γ host loop (allocate ``C + γI`` → LAPACK → dispatch, per γ)
-    with a single device program. Returns ``(n_gammas, d, c)``; γs whose
-    system is singular come back as NaNs (caller falls back to the
-    eigendecomposition path).
+    One ``pallas_call`` whose grid walks γ-blocks: each step shifts the
+    diagonal of C by its block of γs (up to ``gamma_block``; wide systems go
+    one per step to fit VMEM), factors all of them as one batched blocked
+    Cholesky, and runs the batched substitution — replacing the per-γ host
+    loop (allocate ``C + γI`` → LAPACK → dispatch, per γ) with a single
+    device program. Returns ``(n_gammas, d, c)``; γs whose system is
+    singular come back as NaNs (caller falls back to the eigendecomposition
+    path).
     """
     d = c.shape[-1]
     n_cls = q.shape[-1]
     n_g = gammas.shape[0]
     if n_g == 0:
         return jnp.zeros((0, d, n_cls), c.dtype)
-    mm = _make_mm(precision)
     bs = min(block, _ceil_mult(d, 8))
     d_p = _ceil_mult(d, bs)
     c_p = _ceil_mult(n_cls, 8)
-    bg = min(gamma_block, n_g)
+    bg = _systems_per_step(gamma_block, n_g, d_p, c.dtype)
     n_gp = _ceil_mult(n_g, bg)
     if d_p != d:
         c = _pad_spd(c[None], d_p)[0]
@@ -376,18 +429,18 @@ def multi_gamma_solve(c: jax.Array, q: jax.Array, gammas: jax.Array, *,
     if n_gp != n_g:
         gammas = jnp.concatenate(
             [gammas, jnp.broadcast_to(gammas[-1], (n_gp - n_g,))])
-    gammas = gammas.astype(c.dtype).reshape(n_gp // bg, bg)
+    gammas = gammas.astype(c.dtype)
 
-    def kernel(c_ref, q_ref, g_ref, w_ref):
-        cc = c_ref[...]
-        g = g_ref[...][0]                                   # (bg,)
-        diag = jnp.arange(d_p)
-        eye = (diag[:, None] == diag[None, :]).astype(cc.dtype)
-        a = cc[None] + g[:, None, None] * eye[None]
-        l, inv_blocks = _factor_panels(a, bs, mm)
+    def kernel(c_ref, q_ref, g_ref, w_ref, s_ref):
+        eye = (_iota((d_p, d_p), 0) == _iota((d_p, d_p), 1)).astype(c.dtype)
+        base = pl.program_id(0) * bg
+        for t in range(bg):
+            s_ref[t] = c_ref[...] + g_ref[base + t] * eye
+        inv_blocks = _factor_panels(s_ref, bs, precision)
         qb = jnp.broadcast_to(q_ref[...][None], (bg, d_p, c_p))
-        w_ref[...] = _solve_panels(l, qb, bs, mm,
-                                   inv_blocks=inv_blocks)[None]
+        xs = _solve_panels(s_ref, qb, bs, precision, inv_blocks)
+        for k, xk in enumerate(xs):
+            w_ref[:, k * bs:(k + 1) * bs] = xk
 
     out = pl.pallas_call(
         kernel,
@@ -395,13 +448,15 @@ def multi_gamma_solve(c: jax.Array, q: jax.Array, gammas: jax.Array, *,
         in_specs=[
             pl.BlockSpec((d_p, d_p), lambda i: (0, 0)),
             pl.BlockSpec((d_p, c_p), lambda i: (0, 0)),
-            pl.BlockSpec((1, bg), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, bg, d_p, c_p), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_gp // bg, bg, d_p, c_p), c.dtype),
+        out_specs=pl.BlockSpec((bg, d_p, c_p), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_gp, d_p, c_p), c.dtype),
+        scratch_shapes=[pltpu.VMEM((bg, d_p, d_p), c.dtype)],
+        compiler_params=_whole_resident_params(),
         interpret=interpret,
     )(c, q, gammas)
-    return out.reshape(n_gp, d_p, c_p)[:n_g, :d, :n_cls]
+    return out[:n_g, :d, :n_cls]
 
 
 # ---------------------------------------------------------------------------
@@ -430,32 +485,6 @@ def multi_gamma_solve(c: jax.Array, q: jax.Array, gammas: jax.Array, *,
 # :func:`streamed_cholesky`.
 # ---------------------------------------------------------------------------
 
-_DIMS_NN = (((1,), (0,)), ((), ()))    # a @ b
-_DIMS_NT = (((1,), (1,)), ((), ()))    # a @ bᵀ
-_DIMS_TN = (((0,), (0,)), ((), ()))    # aᵀ @ b
-
-
-def _make_mm2(precision: str, dims):
-    """Unbatched 2-D tile matmul at the requested precision (see _make_mm)."""
-
-    def mm(a, b):
-        return lax.dot_general(a, b, dims, preferred_element_type=a.dtype)
-
-    if precision != "f32_x2":
-        return mm
-
-    def mm_x2(a, b):
-        ah, al = _split(a)
-        bh, bl = _split(b)
-        hi = lax.dot_general(ah, bh, dims, preferred_element_type=a.dtype)
-        mid = (lax.dot_general(ah, bl, dims, preferred_element_type=a.dtype)
-               + lax.dot_general(al, bh, dims,
-                                 preferred_element_type=a.dtype))
-        return hi + mid
-
-    return mm_x2
-
-
 def panel_width(rows: int, cap: int = DEFAULT_STREAM_BLOCK) -> int:
     """Largest panel width ≤ ``cap`` that divides ``rows`` — panels must tile
     the shard rows exactly so every panel has a single static owner shard."""
@@ -475,9 +504,9 @@ def panel_factor(diag: jax.Array, *, interpret: bool = False):
     b = diag.shape[-1]
 
     def kernel(d_ref, l_ref, z_ref):
-        l = _factor_tile(d_ref[...][None])
-        l_ref[...] = l[0]
-        z_ref[...] = _tri_inv_tile(l)[0]
+        l = _factor_tile(d_ref[...])
+        l_ref[...] = l
+        z_ref[...] = _tri_inv_tile(l)
 
     return pl.pallas_call(
         kernel,
@@ -494,7 +523,7 @@ def panel_tri_inv(l: jax.Array, *, interpret: bool = False) -> jax.Array:
     b = l.shape[-1]
 
     def kernel(l_ref, z_ref):
-        z_ref[...] = _tri_inv_tile(l_ref[...][None])[0]
+        z_ref[...] = _tri_inv_tile(l_ref[...])
 
     return pl.pallas_call(
         kernel,
@@ -512,7 +541,7 @@ def panel_trsm(raw: jax.Array, zinv: jax.Array, *, precision: str = "native",
     the local column slab through VMEM against the replicated (b, b) inverse."""
     r, b = raw.shape
     rb = panel_width(r, row_block)
-    mm = _make_mm2(precision, _DIMS_NT)
+    mm = _make_mm(precision, _DIMS_NT)
 
     def kernel(a_ref, z_ref, o_ref):
         o_ref[...] = mm(a_ref[...], z_ref[...])
@@ -547,7 +576,7 @@ def panel_update(trail: jax.Array, lp: jax.Array, pt: jax.Array, *,
     b = lp.shape[-1]
     rb = panel_width(r, row_block)
     cb = panel_width(w, col_block)
-    mm = _make_mm2(precision, _DIMS_NT)
+    mm = _make_mm(precision, _DIMS_NT)
 
     def kernel(t_ref, l_ref, p_ref, o_ref):
         o_ref[...] = t_ref[...] - mm(l_ref[...], p_ref[...])
@@ -590,7 +619,7 @@ def tile_cholesky_factor(tile, *, shard, n_shards: int, gather, block: int,
     """
     r, d_p = tile.shape
     b = block
-    mm_nt = _make_mm2(precision, _DIMS_NT)
+    mm_nt = _make_mm(precision, _DIMS_NT)
     rows_g = shard * r + jnp.arange(r)          # global row ids of this tile
     work = tile
     zs = []
@@ -602,8 +631,8 @@ def tile_cholesky_factor(tile, *, shard, n_shards: int, gather, block: int,
         if use_kernel:
             l_d, z = panel_factor(diag, interpret=interpret)
         else:
-            l_d = _factor_tile(diag[None])[0]
-            z = _tri_inv_tile(l_d[None])[0]
+            l_d = _factor_tile(diag)
+            z = _tri_inv_tile(l_d)
         zs.append(z)
         if use_kernel:
             colv = panel_trsm(work[:, o:o + b], z, precision=precision,
@@ -647,8 +676,8 @@ def tile_cholesky_solve(tile_l, q_tile, zs=None, *, shard, n_shards: int,
     r, d_p = tile_l.shape
     cdim = q_tile.shape[-1]
     b = block
-    mm_nn = _make_mm2(precision, _DIMS_NN)
-    mm_tn = _make_mm2(precision, _DIMS_TN)
+    mm_nn = _make_mm(precision, _DIMS_NN)
+    mm_tn = _make_mm(precision, _DIMS_TN)
     rows_g = shard * r + jnp.arange(r)
     panels = list(range(d_p // b))
     if zs is None:
@@ -660,7 +689,7 @@ def tile_cholesky_solve(tile_l, q_tile, zs=None, *, shard, n_shards: int,
             if use_kernel:
                 zs.append(panel_tri_inv(diagl, interpret=interpret))
             else:
-                zs.append(_tri_inv_tile(diagl[None])[0])
+                zs.append(_tri_inv_tile(diagl))
     y = jnp.zeros((d_p, cdim), q_tile.dtype)
     for p in panels:
         o = p * b
@@ -732,42 +761,44 @@ def streamed_cholesky_solve(l: jax.Array, b: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
-def _rank_update_kernel(l_ref, xt_ref, o_ref):
+def _rank_update_kernel(l_ref, xt_ref, o_ref, x_ref):
     """Householder column sweep folding ``xtᵀ`` rows into a lower factor.
 
-    Whole-resident: L (d_p, d_p) and the stacked update tail xt (d_p, k_p)
-    live in VMEM for the entire sweep — one kernel launch for the whole
-    rank-k update instead of k rank-1 sweeps (or a host-driven loop). Each
-    column step annihilates all k update entries with a single
-    (k+1)-reflection; masked full-width updates keep every iteration
-    static-shape under ``fori_loop``. Zero update rows (s == 0 — including
-    every identity-tail padding column) reduce to r = |a| with vanishing
-    corrections, so padding needs no masking of its own.
+    Whole-resident: L (d_p, d_p), swept in place in ``o_ref``, and the
+    stacked update tail xt (d_p, k_p), in the ``x_ref`` scratch, live in
+    VMEM for the entire sweep — one kernel launch for the whole rank-k
+    update instead of k rank-1 sweeps (or a host-driven loop). Each column
+    step annihilates all k update entries with a single (k+1)-reflection;
+    masked full-width updates keep every iteration static-shape under
+    ``fori_loop``. Zero update rows (s == 0 — including every identity-tail
+    padding column) reduce to r = |a| with vanishing corrections, so padding
+    needs no masking of its own.
     """
     dp = l_ref.shape[-1]
-    rows = jnp.arange(dp)
+    rows = _iota((dp, 1), 0)
+    lanes = _iota((dp, dp), 1)
+    o_ref[...] = l_ref[...]
+    x_ref[...] = xt_ref[...]
 
     def body(i, carry):
-        l, xt = carry
-        w = xt[i, :]
-        s = jnp.sum(w * w)
+        l, xt = o_ref[...], x_ref[...]
+        w = _pick_row(xt, i)                         # (1, k_p)
+        s = jnp.sum(w * w, axis=-1, keepdims=True)   # (1, 1)
         s_ = jnp.where(s > 0, s, 1.0)      # w == 0 ⇒ t == 0, updates vanish
-        a = l[i, i]
+        col = _pick_col(l, i)                        # (d_p, 1)
+        a = _pick_row(col, i)                        # (1, 1)
         r = jnp.sqrt(a * a + s)
         amr = -s / (r + a)                 # a − r without cancellation
         beta = (r + a) / (r * s_)          # 2 / uᵀu for u = [a−r; w]
         below = rows > i
-        col = l[:, i]
-        t = amr * col + xt @ w
+        t = amr * col + jnp.sum(xt * w, axis=-1, keepdims=True)
         new_col = jnp.where(below, col - (beta * amr) * t, col)
         new_col = jnp.where(rows == i, r, new_col)
-        l = l.at[:, i].set(new_col)
-        xt = jnp.where(below[:, None],
-                       xt - (beta * t)[:, None] * w[None, :], xt)
-        return l, xt
+        o_ref[...] = jnp.where(lanes == i, new_col, l)
+        x_ref[...] = jnp.where(below, xt - (beta * t) * w, xt)
+        return carry
 
-    l, _ = lax.fori_loop(0, dp, body, (l_ref[...], xt_ref[...]))
-    o_ref[...] = l
+    lax.fori_loop(0, dp, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -797,11 +828,9 @@ def chol_rank_update(l: jax.Array, xs: jax.Array, *,
     xt = jnp.pad(xs.T.astype(l.dtype), ((0, d_p - d), (0, k_p - k)))
     out = pl.pallas_call(
         _rank_update_kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((d_p, d_p), lambda i: (0, 0)),
-                  pl.BlockSpec((d_p, k_p), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((d_p, d_p), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d_p, d_p), l.dtype),
+        scratch_shapes=[pltpu.VMEM((d_p, k_p), l.dtype)],
+        compiler_params=_whole_resident_params(),
         interpret=interpret,
     )(lp, xt)
     return out[:d, :d]

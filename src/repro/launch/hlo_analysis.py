@@ -389,9 +389,8 @@ def peak_aval_bytes(fn, *args, **kwargs) -> Tuple[int, str]:
     can only shrink it). Returns ``(bytes, shape_str)`` for the peak value.
     """
     import jax
+    import jax.extend.core as core
     import numpy as np
-
-    core = jax.core
 
     def aval_bytes(v):
         aval = getattr(v, "aval", None)

@@ -32,16 +32,30 @@ MULTI_POD_SHAPE = (2, 16, 16)
 MULTI_POD_AXES = ("pod", "data", "model")
 
 
+def auto_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """Every mesh of the repo is built here, with all axes Auto.
+
+    ``jax.make_mesh`` makes Explicit axes by default, and the activation
+    policy's ``with_sharding_constraint`` (``core/act.py``) and the
+    shard_map collectives are written for Auto ones. ``devices`` defaults
+    to the first ``prod(shape)`` devices of the process.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = MULTI_POD_AXES if multi_pod else SINGLE_POD_AXES
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
-    """Degenerate mesh over whatever devices exist (CPU smoke/examples)."""
+    """(n, 1) mesh over every device of the process: 'data' spans them."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"))
 
 
 def batch_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

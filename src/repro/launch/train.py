@@ -15,8 +15,10 @@ Usage (CPU example — reduced config):
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import os
+import pathlib
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -26,13 +28,36 @@ from repro.config import FLConfig
 from repro.configs.registry import get_config
 from repro.core import act
 from repro.data import synthetic as D
-from repro.fl.api import AFLClient, AFLServer, ShardedCoordinator
+from repro.fl.api import (AFLClient, AFLServer, ClientReport,
+                          ShardedCoordinator)
 from repro.launch import mesh as M
 from repro.launch import sharding as SH
 from repro.launch import steps as ST
 from repro.launch.inputs import sample_batch
 from repro.models import transformer as T
 from repro.optim import wsd_schedule
+
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets nothing. Otherwise the cache lives at ``<checkout>/.jax_cache`` — a
+    fixed path, since a cache directory that moves never hits.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
+
+
+class AnalyticRun(NamedTuple):
+    accuracy: float
+    train_seconds: float
+    head: np.ndarray          # (d, C) f64, the coordinator's solve
+    report: ClientReport      # the client's one upload (its Gram statistics)
 
 
 def _batches(ds: D.Dataset, batch: int):
@@ -53,7 +78,8 @@ def _embed_fn(params, cfg, mesh):
 
 
 def run_analytic(cfg, mesh, train_ds, test_ds, fl: FLConfig, batch: int,
-                 use_kernel: bool = False, server_url: str = ""):
+                 use_kernel: bool = False,
+                 server_url: str = "") -> AnalyticRun:
     """AFL on-device: one epoch of forwards, one aggregation collective.
 
     Drives the canonical API end to end: an :class:`~repro.fl.api.AFLClient`
@@ -95,7 +121,8 @@ def run_analytic(cfg, mesh, train_ds, test_ds, fl: FLConfig, batch: int,
                                    axis_names=naxes)
     else:
         coord = AFLServer(cfg.d_model, cfg.num_classes, gamma=fl.gamma)
-    coord.submit(client.report())
+    report = client.report()
+    coord.submit(report)
     w = coord.solve(target_gamma=0.0)
     train_s = time.perf_counter() - t0
     # evaluate
@@ -105,7 +132,8 @@ def run_analytic(cfg, mesh, train_ds, test_ds, fl: FLConfig, batch: int,
         pred = np.argmax(np.asarray(emb) @ np.asarray(w), -1)
         correct += int((pred == labels).sum())
         total += len(labels)
-    return float(correct / max(total, 1)), train_s
+    return AnalyticRun(float(correct / max(total, 1)), train_s,
+                       np.asarray(w, np.float64), report)
 
 
 def run_gradient(cfg, mesh, train_ds, test_ds, fl: FLConfig, batch: int,
@@ -165,6 +193,7 @@ def main() -> None:
                          "of aggregating in-process (see launch/serve.py "
                          "--federation)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -184,9 +213,9 @@ def main() -> None:
     train_ds, test_ds = D.train_test_split(ds, 0.25, seed=0)
     fl = FLConfig(gamma=args.gamma)
     if args.mode == "analytic":
-        acc, dt = run_analytic(cfg, mesh, train_ds, test_ds, fl, args.batch,
-                               use_kernel=args.kernel,
-                               server_url=args.server_url)
+        acc, dt, _, _ = run_analytic(cfg, mesh, train_ds, test_ds, fl,
+                                     args.batch, use_kernel=args.kernel,
+                                     server_url=args.server_url)
         where = f" via {args.server_url}" if args.server_url else ""
         print(f"AFL analytic: acc={acc:.4f} train_time={dt:.2f}s (one epoch, "
               f"single aggregation{where})")

@@ -14,10 +14,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-from repro.core import act
-
 from repro.config import MoEConfig
+from repro.core import act
 from repro.models import layers as L
 
 
@@ -134,7 +132,7 @@ def _ffn_shard_map(p, groups, slot, weight, e, cap, activation):
     wrow = P(None, tp, None)   # (E, f, d)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(
             {k: (wrow if k == "w_down" else wcol)
              for k in ("w_up", "w_down", *(("w_gate",) if "w_gate" in p else ()))},

@@ -1,9 +1,10 @@
 """Multi-device semantics tests, run in subprocesses so the forced device
 count cannot leak into (or be blocked by) the main test process's jax.
 
-Covers the two places where the distributed path must equal the host math:
+Covers the places where the distributed path must equal the host math:
   1. federated_solve (one psum over the mesh) == core.analytic host solve.
   2. shard_map MoE FFN == the single-program dense path.
+  3. the Gram kernel on mesh-sharded rows == the one-device fold.
 """
 
 import os
@@ -96,7 +97,7 @@ def test_analytic_train_step_multidevice_lowering():
     from repro.launch.inputs import sample_batch
     from repro.models import transformer as T
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = MM.auto_mesh((4, 2), ("data", "model"))
     cfg = get_config("granite_moe_3b_a800m").reduced(num_classes=8)
     params = T.init_params(jax.random.key(0), cfg)
     state = streaming.init_state(cfg.d_model, cfg.num_classes)
@@ -120,4 +121,37 @@ def test_analytic_train_step_multidevice_lowering():
     err = np.abs(g - np.asarray(ref.gram)).max() / max(np.abs(g).max(), 1)
     assert err < 5e-5, err
     print("ok", err)
+    """)
+
+
+def test_gram_kernel_folds_sharded_rows_per_device():
+    """A Mosaic kernel cannot be partitioned by XLA, so ops.gram_update
+    folds rows sharded over a mesh on each device and psums the partials;
+    the replicated result equals the one-device fold."""
+    _run("""
+    import jax, numpy as np
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.kernels import ops
+    from repro.launch import mesh as MM
+
+    mesh = MM.auto_mesh((4, 2), ("data", "model"))
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((64, 48)).astype(np.float32)
+    y = np.eye(5, dtype=np.float32)[rng.integers(0, 5, 64)]
+    rows = NamedSharding(mesh, P("data"))
+    g, q = ops.gram_update(jax.device_put(x, rows), jax.device_put(y, rows))
+    assert len(g.sharding.device_set) == 8, g.sharding
+    g1, q1 = ops.gram_update(jnp.asarray(x), jnp.asarray(y))
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g1), rtol=1e-5,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(q), np.asarray(q1), rtol=1e-5,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(g), x.T.astype(np.float64) @ x,
+                               rtol=1e-5, atol=1e-4)
+    # a stream of batches reuses one compiled fold
+    ops.gram_update(jax.device_put(x, rows), jax.device_put(y, rows))
+    info = ops._sharded_gram.cache_info()
+    assert (info.misses, info.hits) == (1, 1), info
+    print("ok")
     """)

@@ -10,9 +10,8 @@ from repro.configs.registry import get_config
 from repro.launch import sharding as SH
 from repro.models import transformer as T
 
-# AbstractMesh takes a single shape tuple of (axis_name, size) pairs.
-MESH_1POD = AbstractMesh((("data", 16), ("model", 16)))
-MESH_2POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(arch, mesh):
