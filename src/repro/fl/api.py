@@ -33,9 +33,11 @@ FederationService`, the in-proc/HTTP transports, and the wire-true
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import struct
+import sys
 import uuid
 import zlib
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Protocol,
@@ -43,6 +45,7 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Protocol,
 
 import numpy as np
 
+from repro import scopes
 from repro.core.engine import (AnalyticEngine, Factorization, SuffStats,
                                SweepFactorization, SweepRefreshNeeded)
 from repro.fl.errors import (BadRequest, Backpressure, DuplicateClient,
@@ -212,6 +215,14 @@ class ClientReport:
 # ---------------------------------------------------------------------------
 
 
+def _span(name: str):
+    """A host span on the profiler's clock, beside the device trace; none
+    where JAX was never imported, since no profiler can be running then."""
+    jax = sys.modules.get("jax")
+    return (jax.profiler.TraceAnnotation(name) if jax is not None
+            else contextlib.nullcontext())
+
+
 class AFLClient:
     """One client's local stage, start to finish.
 
@@ -272,6 +283,10 @@ class AFLClient:
 
     def update(self, x, y_onehot) -> "AFLClient":
         """Fold one batch of local data into the running statistics."""
+        with _span(scopes.FOLD_SPAN):
+            return self._update(x, y_onehot)
+
+    def _update(self, x, y_onehot) -> "AFLClient":
         x = self._embed(x)
         dim = int(np.asarray(x.shape)[-1])
         classes = int(np.asarray(y_onehot.shape)[-1])
@@ -288,8 +303,9 @@ class AFLClient:
                 # a ≥ d-row root is no cheaper than a refactor — stop tracking
                 self._root_blocks = None
             elif n:
-                self._root_blocks.append(
-                    np.asarray(x, np.float64).reshape(-1, dim))
+                with _span(scopes.FOLD_ROOT_SPAN):   # waits for the device
+                    self._root_blocks.append(
+                        np.asarray(x, np.float64).reshape(-1, dim))
         return self
 
     def report(self) -> ClientReport:

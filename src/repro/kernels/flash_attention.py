@@ -159,6 +159,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out.reshape(b, hq, sq_p, d_p)[:, :, :sq, :d]
 

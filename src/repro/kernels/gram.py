@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import scopes
+
 DEFAULT_BLOCK_D = 128   # output tile side (MXU lane-aligned)
 DEFAULT_BLOCK_N = 512   # reduction chunk (sublane multiple)
 
@@ -84,6 +86,7 @@ def _gram_kernel(xi_ref, xj_ref, y_ref, g_ref, q_ref, g_acc, q_acc, *,
 @functools.partial(
     jax.jit, static_argnames=("block_d", "block_n", "interpret", "out_dtype")
 )
+@jax.named_scope(scopes.GRAM_FOLD)
 def gram_update(
     x: jax.Array,
     y: jax.Array,
@@ -144,6 +147,7 @@ def gram_update(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="gram_update",
     )(x, x, y)
     return g[:d, :d], q[:d, :c]
 

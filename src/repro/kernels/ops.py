@@ -14,6 +14,7 @@ import functools
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import scopes
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gram as _gram
 from repro.kernels import solve as _solve
@@ -49,7 +50,9 @@ def _sharded_gram(mesh, rows, kw_items):
     kw = dict(kw_items)
 
     def local(xs, ys):
-        return jax.lax.psum(_gram.gram_update(xs, ys, **kw), rows)
+        stats = _gram.gram_update(xs, ys, **kw)
+        with jax.named_scope(scopes.GRAM_PSUM):
+            return jax.lax.psum(stats, rows)
 
     return jax.jit(jax.shard_map(local, mesh=mesh,
                                  in_specs=(P(rows), P(rows)), out_specs=P(),

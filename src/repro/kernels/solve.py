@@ -334,6 +334,7 @@ def blocked_cholesky(a: jax.Array, *, block: int = DEFAULT_BLOCK,
         out_shape=jax.ShapeDtypeStruct((m_p, d_p, d_p), a.dtype),
         compiler_params=_whole_resident_params(),
         interpret=interpret,
+        name="blocked_cholesky",
     )(a)
     return out[:m, :d, :d]
 
@@ -389,6 +390,7 @@ def cholesky_solve(l: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK,
         out_shape=jax.ShapeDtypeStruct((m_p, d_p, c_p), b.dtype),
         compiler_params=_whole_resident_params(),
         interpret=interpret,
+        name="cholesky_solve",
     )(l, b)
     return out[:m, :d, :c]
 
@@ -455,6 +457,7 @@ def multi_gamma_solve(c: jax.Array, q: jax.Array, gammas: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bg, d_p, d_p), c.dtype)],
         compiler_params=_whole_resident_params(),
         interpret=interpret,
+        name="multi_gamma_solve",
     )(c, q, gammas)
     return out[:n_g, :d, :n_cls]
 
@@ -513,6 +516,7 @@ def panel_factor(diag: jax.Array, *, interpret: bool = False):
         out_shape=(jax.ShapeDtypeStruct((b, b), diag.dtype),
                    jax.ShapeDtypeStruct((b, b), diag.dtype)),
         interpret=interpret,
+        name="panel_factor",
     )(diag)
 
 
@@ -529,6 +533,7 @@ def panel_tri_inv(l: jax.Array, *, interpret: bool = False) -> jax.Array:
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, b), l.dtype),
         interpret=interpret,
+        name="panel_tri_inv",
     )(l)
 
 
@@ -556,6 +561,7 @@ def panel_trsm(raw: jax.Array, zinv: jax.Array, *, precision: str = "native",
         out_specs=pl.BlockSpec((rb, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, b), raw.dtype),
         interpret=interpret,
+        name="panel_trsm",
     )(raw, zinv)
 
 
@@ -592,6 +598,7 @@ def panel_update(trail: jax.Array, lp: jax.Array, pt: jax.Array, *,
         out_specs=pl.BlockSpec((rb, cb), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, w), trail.dtype),
         interpret=interpret,
+        name="panel_update",
     )(trail, lp, pt)
 
 
@@ -832,5 +839,6 @@ def chol_rank_update(l: jax.Array, xs: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((d_p, k_p), l.dtype)],
         compiler_params=_whole_resident_params(),
         interpret=interpret,
+        name="chol_rank_update",
     )(lp, xt)
     return out[:d, :d]

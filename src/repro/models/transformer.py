@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes
 from repro.config import ModelConfig
 from repro.core import act
 from repro.models import layers as L
@@ -87,6 +88,7 @@ def _init_block(key, cfg: ModelConfig, cross_attn: bool = False):
     return p
 
 
+@jax.named_scope(scopes.FFN)
 def _block_ffn(p, cfg: ModelConfig, x):
     x = act.constrain_bsd(x)
     h = L.norm_apply(p["ln2"], x, cfg.norm_eps, cfg.norm)
@@ -102,6 +104,16 @@ def _block_ffn(p, cfg: ModelConfig, x):
 def _block_fwd(p, cfg: ModelConfig, x, positions, window, theta,
                *, causal=True, kv_cache=None, pos=None, memory_kv=None):
     """One attention block. Returns (x, new_kv or computed kv)."""
+    x, new_kv = _block_mixer(p, cfg, x, positions, window, theta,
+                             causal=causal, kv_cache=kv_cache, pos=pos,
+                             memory_kv=memory_kv)
+    return _block_ffn(p, cfg, x), new_kv
+
+
+@jax.named_scope(scopes.MIXER)
+def _block_mixer(p, cfg: ModelConfig, x, positions, window, theta,
+                 *, causal, kv_cache, pos, memory_kv):
+    """The attention sub-layers of a block: (x, new_kv or computed kv)."""
     dims = _attn_dims(cfg)
     x = act.constrain_bsd(x)
     h = L.norm_apply(p["ln1"], x, cfg.norm_eps, cfg.norm)
@@ -110,10 +122,10 @@ def _block_fwd(p, cfg: ModelConfig, x, positions, window, theta,
     k = act.constrain_heads(k)
     v = act.constrain_heads(v)
     if kv_cache is None:
-        attn = L.sdpa(q, k, v, causal=causal, window=window,
-                      softcap=cfg.logit_softcap)
+        with jax.named_scope(scopes.SEQMIX):
+            attn = L.sdpa(q, k, v, causal=causal, window=window,
+                          softcap=cfg.logit_softcap)
         new_kv = (k, v)
-        q_offset = 0
     else:
         ck, cv = kv_cache
         clen = ck.shape[2]
@@ -129,17 +141,19 @@ def _block_fwd(p, cfg: ModelConfig, x, positions, window, theta,
         win = jnp.where((win > 0) & (clen <= win), 0, win)
         ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, slot, 0))
         cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, slot, 0))
-        attn = L.sdpa(q, ck, cv, causal=True, window=win, q_offset=pos,
-                      softcap=cfg.logit_softcap)
+        with jax.named_scope(scopes.SEQMIX):
+            attn = L.sdpa(q, ck, cv, causal=True, window=win, q_offset=pos,
+                          softcap=cfg.logit_softcap)
         new_kv = (ck, cv)
     x = x + L.attn_out(p["attn"], attn)
     if memory_kv is not None:  # cross attention (enc-dec)
         hx = L.norm_apply(p["ln_x"], x, cfg.norm_eps, cfg.norm)
         qx, _, _ = L.qkv_project(p["xattn"], dims, hx, positions, None)
         mk, mv = memory_kv
-        xattn = L.sdpa(qx, mk, mv, causal=False, window=None)
+        with jax.named_scope(scopes.SEQMIX):
+            xattn = L.sdpa(qx, mk, mv, causal=False, window=None)
         x = x + L.attn_out(p["xattn"], xattn)
-    return _block_ffn(p, cfg, x), new_kv
+    return x, new_kv
 
 
 def _memory_kv(p, cfg: ModelConfig, memory):
@@ -167,6 +181,7 @@ def _init_common(key, cfg: ModelConfig):
     return p
 
 
+@jax.named_scope(scopes.EMBED)
 def embed_inputs(params: Params, cfg: ModelConfig, batch):
     """tokens (+ optional VLM prefix) → (x (B,S,D), positions (B,S))."""
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
@@ -178,9 +193,15 @@ def embed_inputs(params: Params, cfg: ModelConfig, batch):
     return act.constrain_bsd(x), positions
 
 
+@jax.named_scope(scopes.POOL)
 def pool(hidden: jax.Array) -> jax.Array:
     """Sequence-mean embedding for the AFL analytic head."""
     return jnp.mean(hidden, axis=1)
+
+
+@jax.named_scope(scopes.FINAL_NORM)
+def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
 
 
 def lm_logits(params: Params, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
@@ -207,7 +228,7 @@ def _dense_forward(params, cfg, x, positions, causal=True):
         return h, None
 
     x, _ = jax.lax.scan(body, x, (params["layers"], window, theta))
-    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
+    return _final_norm(params, cfg, x)
 
 
 def _dense_prefill(params, cfg, x, positions, max_seq):
@@ -225,7 +246,7 @@ def _dense_prefill(params, cfg, x, positions, max_seq):
 
     x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], window, theta))
     cache = {"k": ks, "v": vs}  # (L, B, Hk, max_seq, hd)
-    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm), cache
+    return _final_norm(params, cfg, x), cache
 
 
 def _dense_decode(params, cfg, x, cache, pos):
@@ -268,7 +289,7 @@ def _dense_decode(params, cfg, x, cache, pos):
 
     x, ks, vs = jax.lax.fori_loop(
         0, cfg.num_layers, body, (x, cache["k"], cache["v"]))
-    x = L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
+    x = _final_norm(params, cfg, x)
     return x, {"k": ks, "v": vs}
 
 
@@ -342,7 +363,7 @@ def _hybrid_forward(params, cfg, x, positions):
         x, _ = jax.lax.scan(group_body, x, params["mamba_groups"])
     if tail:
         x, _ = jax.lax.scan(mamba_body, x, params["mamba_tail"])
-    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
+    return _final_norm(params, cfg, x)
 
 
 def _hybrid_cache(cfg, batch, max_seq, dtype):
@@ -402,7 +423,7 @@ def _hybrid_step(params, cfg, x, positions, cache, pos, max_seq):
     if tail:
         x, tst = jax.lax.scan(mamba_body, x, (params["mamba_tail"], cache["mamba_tail"]))
         new_cache["mamba_tail"] = tst
-    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm), new_cache
+    return _final_norm(params, cfg, x), new_cache
 
 
 # =====================================================================
@@ -443,28 +464,28 @@ def _xlstm_run(params, cfg, x, states=None):
     g, n_groups, tail = _xlstm_split(cfg)
     want_state = states is not None
 
-    def m_body(h, xs):
-        lp, st = xs if want_state else (xs, None)
-        h = act.constrain_bsd(h)
+    @jax.named_scope(scopes.MIXER)
+    def mixer(apply, lp, h, st):
         hin = L.norm_apply(lp["ln"], h, cfg.norm_eps, cfg.norm)
         if want_state:
-            out, nst = X.mlstm_apply(lp["mixer"], hin, cfg.num_heads,
-                                     init_state=st, return_state=True)
+            out, nst = apply(lp["mixer"], hin, cfg.num_heads,
+                             init_state=st, return_state=True)
             return h + out, nst
-        return h + X.mlstm_apply(lp["mixer"], hin, cfg.num_heads), None
+        return h + apply(lp["mixer"], hin, cfg.num_heads), None
+
+    def m_body(h, xs):
+        lp, st = xs if want_state else (xs, None)
+        return mixer(X.mlstm_apply, lp, act.constrain_bsd(h), st)
 
     def group_body(h, xs):
         if want_state:
             gp, sp, gst, sst = xs
             h, new_gst = jax.lax.scan(m_body, h, (gp, gst))
-            hin = L.norm_apply(sp["ln"], h, cfg.norm_eps, cfg.norm)
-            out, new_sst = X.slstm_apply(sp["mixer"], hin, cfg.num_heads,
-                                         init_state=sst, return_state=True)
-            return h + out, (new_gst, new_sst)
+            h, new_sst = mixer(X.slstm_apply, sp, h, sst)
+            return h, (new_gst, new_sst)
         gp, sp = xs
         h, _ = jax.lax.scan(m_body, h, gp)
-        hin = L.norm_apply(sp["ln"], h, cfg.norm_eps, cfg.norm)
-        return h + X.slstm_apply(sp["mixer"], hin, cfg.num_heads), None
+        return mixer(X.slstm_apply, sp, h, None)
 
     new_states: dict = {} if want_state else None
     if n_groups:
@@ -483,7 +504,7 @@ def _xlstm_run(params, cfg, x, states=None):
             new_states["mlstm_tail"] = tst
         else:
             x, _ = jax.lax.scan(m_body, x, params["mlstm_tail"])
-    x = L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
+    x = _final_norm(params, cfg, x)
     return (x, new_states) if want_state else x
 
 
@@ -541,7 +562,7 @@ def _encdec_forward(params, cfg, batch):
         return h, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
-    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
+    return _final_norm(params, cfg, x)
 
 
 def _encdec_cache(cfg, batch, max_seq, dtype):
@@ -573,7 +594,7 @@ def _encdec_prefill(params, cfg, batch, max_seq):
 
     x, (ks, vs, xks, xvs) = jax.lax.scan(body, x, params["layers"])
     cache = {"k": ks, "v": vs, "xk": xks, "xv": xvs}
-    return L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm), cache
+    return _final_norm(params, cfg, x), cache
 
 
 def _encdec_decode(params, cfg, x, cache, pos):
@@ -589,7 +610,7 @@ def _encdec_decode(params, cfg, x, cache, pos):
     x, (ks, vs) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"], cache["xk"], cache["xv"])
     )
-    x = L.norm_apply(params["final_norm"], x, cfg.norm_eps, cfg.norm)
+    x = _final_norm(params, cfg, x)
     return x, {"k": ks, "v": vs, "xk": cache["xk"], "xv": cache["xv"]}
 
 

@@ -20,6 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.models import layers as L
 
 UP_FACTOR = 2
@@ -83,7 +84,8 @@ def mlstm_apply(p, x, num_heads, *, init_state=None, return_state=False):
         )
     else:
         state = (init_state["c"], init_state["n"], init_state["m"])
-    state, hs = jax.lax.scan(_mlstm_step, state, (q, k, v, i_raw, f_raw))
+    with jax.named_scope(scopes.SEQMIX):
+        state, hs = jax.lax.scan(_mlstm_step, state, (q, k, v, i_raw, f_raw))
     h = hs.transpose(1, 0, 2, 3).reshape(b, s, d_inner)
     out = (h.astype(x.dtype) * jax.nn.silu(gate)) @ p["down"]
     if return_state:
@@ -153,7 +155,8 @@ def slstm_apply(p, x, num_heads, *, init_state=None, return_state=False):
     else:
         state = (init_state["c"], init_state["n"], init_state["h"], init_state["m"])
     step = lambda carry, inp: _slstm_step(p["r"], carry, inp, num_heads, dh)
-    state, hs = jax.lax.scan(step, state, wx)
+    with jax.named_scope(scopes.SEQMIX):
+        state, hs = jax.lax.scan(step, state, wx)
     h = hs.transpose(1, 0, 2, 3).reshape(b, s, d_inner)
     out = (h.astype(x.dtype) * jax.nn.silu(gate)) @ p["down"]
     if return_state:

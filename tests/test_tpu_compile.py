@@ -9,7 +9,9 @@ skips from there where it cannot be described. ``flash_attention`` is left
 out: no model path calls it.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +56,32 @@ KERNELS = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(KERNELS))
-def test_kernel_compiles_for_v5e(one_chip, name):
+# the kernels a wrapper runs, where they are not the wrapper itself
+RUNS = {"streamed_cholesky_d2048": {"panel_factor", "panel_trsm",
+                                    "panel_update"},
+        "streamed_cholesky_solve_d2048": {"panel_tri_inv"}}
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_text(one_chip, name):
     kernel, shapes = KERNELS[name]
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
-    compiled = jax.jit(
-        lambda *a: kernel(*a, interpret=False)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return jax.jit(
+        lambda *a: kernel(*a, interpret=False)).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    assert "tpu_custom_call" in _compiled_text(one_chip, name)
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_keeps_its_name(one_chip, name):
+    """Each kernel's custom call is named after it (``name=`` of its
+    ``pallas_call``), so a device trace finds it by name however the
+    compiler numbers it (``%gram_update.1``)."""
+    text = _compiled_text(one_chip, name)
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)? = [^\n]*"
+                       r"custom-call\(", text, re.M)
+    assert calls and set(calls) == RUNS.get(name, {KERNELS[name][0].__name__})
