@@ -161,16 +161,95 @@ def test_moe_aux_loss_balanced_router():
 
 
 # -------------------------------------------------------------------- xLSTM
-def test_mlstm_state_continuation():
+_mlstm = jax.jit(X.mlstm_apply, static_argnums=2, static_argnames="return_state")
+
+
+def _mlstm_by_token(p, x, h):
+    """``mlstm_apply(..., return_state=True)`` in float64 NumPy as the
+    recurrent form: one stabilized step per token, carrying C (B,H,dh,dh),
+    n (B,H,dh) and m (B,H)."""
+    p = {name: np.asarray(w, np.float64) for name, w in p.items()}
+    x = np.asarray(x, np.float64)
+    b, s, d = x.shape
+    d_inner, dh = X._inner(d, h)
+    up = x @ p["up"]
+    x_in, gate = up[..., :d_inner], up[..., d_inner:]
+    qkv = (x_in @ p["qkv"]).reshape(b, s, 3, h, dh)
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1] / np.sqrt(dh), qkv[:, :, 2]
+    gates = (x_in @ p["if_proj"]).reshape(b, s, 2, h)
+    c_mat, n_vec = np.zeros((b, h, dh, dh)), np.zeros((b, h, dh))
+    m = np.full((b, h), -1e30)
+    hs = []
+    for t in range(s):
+        i_raw, f_raw = gates[:, t, 0], gates[:, t, 1]
+        log_f = -np.logaddexp(0.0, -f_raw)
+        m_new = np.maximum(log_f + m, i_raw)
+        i_g, f_g = np.exp(i_raw - m_new), np.exp(log_f + m - m_new)
+        c_mat = f_g[..., None, None] * c_mat + i_g[..., None, None] * (
+            k[:, t, :, :, None] * v[:, t, :, None, :])
+        n_vec = f_g[..., None] * n_vec + i_g[..., None] * k[:, t]
+        denom = np.maximum(np.abs((n_vec * q[:, t]).sum(-1)), np.exp(-m_new))
+        hs.append(np.einsum("bhd,bhde->bhe", q[:, t], c_mat) / denom[..., None])
+        m = m_new
+    hs = np.stack(hs, 1).reshape(b, s, d_inner)
+    out = (hs * gate / (1.0 + np.exp(-gate))) @ p["down"]
+    return {"out": out, "c": c_mat, "n": n_vec, "m": m}
+
+
+@pytest.mark.parametrize("scale", [0.5, 3.0])
+@pytest.mark.parametrize("s", [1, 7, 64, 65, 130])
+def test_mlstm_chunkwise_matches_recurrence(s, scale):
+    """The chunkwise form computes the recurrent form's function and final
+    state, on one chunk, a chunk and a remainder, and with large (×3) gate
+    inputs.
+
+    Each error is normwise, against the recurrence in float64. It may be
+    1e-5 plus twice what one ulp of noise on the input moves the exact
+    value: at ×3 the outputs reach ~2e3 through small denominators, and the
+    float32 recurrence itself errs by ~2e-5 there."""
+    d, h, b = 32, 4, 2
+    p = X.init_mlstm(jax.random.key(6), d, h, jnp.float32)
+    x = np.asarray(jax.random.normal(jax.random.key(7), (b, s, d)) * scale)
+    want = _mlstm_by_token(p, x, h)
+    away = np.where(np.random.default_rng(0).random(x.shape) < 0.5, -np.inf, np.inf)
+    moved = _mlstm_by_token(p, np.nextafter(x, away.astype(np.float32)), h)
+    out, state = _mlstm(p, jnp.asarray(x), h, return_state=True)
+    got = dict(state, out=out)
+    err = lambda name, y: (np.linalg.norm(np.asarray(y) - want[name])
+                           / np.linalg.norm(want[name]))
+    for name in want:
+        assert err(name, got[name]) <= 1e-5 + 2 * err(name, moved[name]), name
+
+
+@pytest.mark.parametrize("split", [1, 9, 64, 128])
+def test_mlstm_state_continuation(split):
     d, h, b = 32, 4, 2
     p = X.init_mlstm(jax.random.key(0), d, h, jnp.float32)
-    x = jax.random.normal(jax.random.key(1), (b, 20, d)) * 0.5
-    y_full = X.mlstm_apply(p, x, h)
-    y1, st = X.mlstm_apply(p, x[:, :9], h, return_state=True)
-    y2 = X.mlstm_apply(p, x[:, 9:], h, init_state=st)
+    x = jax.random.normal(jax.random.key(1), (b, 130, d)) * 0.5
+    y_full = _mlstm(p, x, h)
+    y1, st = _mlstm(p, x[:, :split], h, return_state=True)
+    y2 = _mlstm(p, x[:, split:], h, init_state=st)
     np.testing.assert_allclose(
         np.asarray(jnp.concatenate([y1, y2], 1)), np.asarray(y_full), atol=1e-5
     )
+
+
+def test_xlstm_decode_continues_prefill():
+    """Through the model's state path: prefill of S tokens then one decode
+    step gives the logits a prefill of S + 1 tokens gives."""
+    from repro.configs import get_config
+    from repro.launch import steps as St
+    from repro.models import transformer as T
+
+    cfg = get_config("xlstm_350m").reduced()
+    params = T.init_params(jax.random.key(8), cfg)
+    tokens = jax.random.randint(jax.random.key(9), (2, 17), 0, cfg.vocab_size)
+    prefill = jax.jit(St.make_prefill_step(cfg, 24))
+    want, _ = prefill(params, {"tokens": tokens})
+    _, cache = prefill(params, {"tokens": tokens[:, :16]})
+    got, _ = jax.jit(St.make_serve_step(cfg))(
+        params, cache, tokens[:, 16], jnp.asarray(16, jnp.int32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
 def test_slstm_state_continuation():
