@@ -228,7 +228,8 @@ def run_cell(cell: Cell, devices, *, seed: int, seconds: float, trace: bool,
         out: Outcome = driver(cell).run(cell, devices, seed=seed,
                                         seconds=seconds, trace_dir=trace_dir,
                                         t0=t0, control=control)
-        tr = Trace.from_dir(trace_dir) if trace else None
+        tr = (Trace.from_dir(trace_dir, out.facts.get("hlo"))
+              if trace else None)
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)

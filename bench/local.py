@@ -21,15 +21,16 @@ import contextlib
 import dataclasses
 import functools
 import gc
-import importlib
 import sys
 import time
+import typing
 
 import numpy as np
 
 from bench import check as C
 from bench import gen
 from bench import precision as PR
+from bench import reference as R
 from bench import trace
 from bench.harness import Check, CompileCounter, Outcome
 
@@ -40,11 +41,40 @@ CHECK_ROWS = 256    # rows of the forward check, spread over every batch
 
 
 def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: the file's
+    keys that name its fields (the others, such as ``family``, are the
+    benchmark's); a field whose type is a dataclass is built from its nested
+    object in the file (``moe`` → ``MoEConfig``, ``ssm`` → ``SSMConfig``)."""
     from repro.config import ModelConfig
 
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    return ModelConfig(**{k: v for k, v in cfg.items() if k in fields})
+    return _dataclass(ModelConfig, cfg, strict=False)
+
+
+def _dataclass(cls, values: dict, *, strict: bool):
+    """``cls`` built from ``values``, nested dataclasses from nested objects;
+    where ``strict``, a key that names no field of ``cls`` is an error."""
+    types = typing.get_type_hints(cls)
+    fields = [f.name for f in dataclasses.fields(cls)]
+    unknown = sorted(set(values) - set(fields))
+    if strict and unknown:
+        raise ValueError(f"{cls.__name__} has no field {', '.join(unknown)}")
+    kw = {}
+    for name in fields:
+        if name not in values:
+            continue
+        value, nested = values[name], _dataclass_type(types[name])
+        if nested is not None and isinstance(value, dict):
+            value = _dataclass(nested, value, strict=True)
+        kw[name] = value
+    return cls(**kw)
+
+
+def _dataclass_type(tp):
+    """The dataclass a field's type names (``Optional[X]`` → X), or None."""
+    for t in (tp, *typing.get_args(tp)):
+        if dataclasses.is_dataclass(t):
+            return t
+    return None
 
 
 class Phases:
@@ -67,10 +97,6 @@ def _is_gram_kernel(op_name: str) -> bool:
     """The Pallas Gram kernel in a device trace: a ``tpu_custom_call`` named
     after ``gram_update`` (``%gram_update.1 = ... custom-call(...)``)."""
     return "tpu_custom_call" in op_name and "gram" in trace.short(op_name)
-
-
-def reference(cfg: dict):
-    return importlib.import_module(f"bench.reference.{cfg['family']}")
 
 
 def seed_key(seed: int):
@@ -109,7 +135,7 @@ def run(cell, devices, *, seed: int, seconds: float, trace_dir, t0: float,
     batch = mix["batch"]
     if batch % chips:
         raise ValueError(f"batch {batch} does not split over {chips} chips")
-    ref = reference(cfg)
+    ref = R.of(cfg)
     counter = CompileCounter().install()
     phases = Phases(t0)
 
@@ -171,8 +197,13 @@ def run(cell, devices, *, seed: int, seconds: float, trace_dir, t0: float,
                 jax.block_until_ready(client._stats)
             t_end = time.perf_counter()
         counter.open = False
+        hlo = {}
         if trace_dir:
             jax.profiler.stop_trace()
+            # the forward's compiled HLO, which names the scope of each of
+            # its operations (the program the window ran: no new compile)
+            hlo[forward_program] = embed.lower(
+                params, feed_x[0]).compile().as_text()
     phases.mark("window")
     steps = i - WARMUP_BATCHES
     window_s = t_end - t_start
@@ -210,7 +241,7 @@ def run(cell, devices, *, seed: int, seconds: float, trace_dir, t0: float,
     facts = {"steps": steps, "samples": samples, "window_s": window_s,
              "samples_per_s": samples / window_s, "rows_per_chip": batch // chips,
              "seq": mix["seq"], "chips": chips,
-             "forward_program": forward_program,
+             "forward_program": forward_program, "hlo": hlo,
              "gram_kernel_match": _is_gram_kernel,
              "compiles_in_window": counter.count}
     return Outcome(
@@ -227,7 +258,7 @@ def _checks(cell, seed, tokens, onehot, pre_x, pre_y, emb, report, head,
     import jax.numpy as jnp
 
     cfg, mix, lim = cell.cfg, cell.mix, cell.limits
-    ref = reference(cfg)
+    ref = R.of(cfg)
     pool, batch = len(tokens), mix["batch"]
     blocks = list(pre_x) + emb
     y_blocks = list(pre_y) + [onehot[i % pool] for i in range(len(emb))]

@@ -50,6 +50,22 @@ def init(cfg: dict, key) -> dict:
             "layers": layers}
 
 
+def forward_ops(cfg: dict, seq: int) -> float:
+    """Operations of the forward for one sequence of ``seq`` tokens: the
+    projections and the MLP of every layer, and the attention core over the
+    whole S × S square (the embedding lookup, norms and pooling not
+    counted)."""
+    d, L, f = cfg["d_model"], cfg["num_layers"], cfg["d_ff"]
+    h, hk = cfg["num_heads"], cfg["num_kv_heads"]
+    hd = cfg.get("head_dim") or d // h
+    attn_params = d * h * hd + 2 * d * hk * hd + h * hd * d
+    mlp_params = (3 if cfg.get("activation", "swiglu") == "swiglu" else 2) * d * f
+    matmuls = 2 * L * (attn_params + mlp_params) * seq
+    # scores QKᵀ and the weighted sum PV over the whole S×S square
+    attention = 4 * L * seq * seq * h * hd
+    return matmuls + attention
+
+
 def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * scale.astype(F32)

@@ -62,6 +62,27 @@ def init(cfg: dict, key) -> dict:
     return params
 
 
+def forward_ops(cfg: dict, seq: int) -> float:
+    """Operations of the forward for one sequence of ``seq`` tokens: the
+    projections of every block and, per head, the recurrent memory's work,
+    all per token (the embedding lookup, norms and pooling not counted)."""
+    d, h, L = cfg["d_model"], cfg["num_heads"], cfg["num_layers"]
+    di = cfg["up_factor"] * d
+    dh = di // h
+    n_s = L // cfg["slstm_every"]
+    n_m = L - n_s
+    m_params = d * 2 * di + di * 3 * di + di * 2 * h + di * d
+    s_params = d * 2 * di + di * 4 * di + di * d
+    # mLSTM matrix memory per head: the update C ← f·C + i·k vᵀ (one
+    # multiply-add per entry) and the readout qᵀC (one per entry)
+    m_memory = 2 * dh * dh + 2 * dh * dh
+    # sLSTM recurrent gates: R (4, H, dh, dh) against h_{t-1}
+    s_recurrent = 2 * 4 * dh * dh
+    per_token = (2 * (n_m * m_params + n_s * s_params)
+                 + h * (n_m * m_memory + n_s * s_recurrent))
+    return per_token * seq
+
+
 def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * scale.astype(F32)

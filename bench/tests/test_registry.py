@@ -8,6 +8,7 @@ import re
 import pytest
 
 from bench import harness
+from bench import reference
 
 BENCH = harness.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -52,6 +53,7 @@ def test_configs_hold_their_own_files():
         assert cfg["name"] == c["name"]
         assert os.path.exists(os.path.join(
             harness.BENCH_DIR, "reference", cfg["family"] + ".py"))
+        assert callable(reference.of(cfg).forward_ops)
 
 
 def test_unknown_workload_and_device_kind_are_refused():

@@ -115,3 +115,168 @@ def test_recorded_trace():
     assert set(gaps) == {"bench.fold"}
     assert gaps["bench.fold"] == pytest.approx(tr.window_s() - tr.busy_s())
 
+
+
+# -- scopes -----------------------------------------------------------------
+
+def _scoped_trace():
+    E = T.Event
+    # window 0..10 s. Chip 0: a layer loop (0.5–8.5, scope "mixer") holds
+    # its body's operations; one seqmix op runs after the window. Chip 1:
+    # an ffn op straddles the window's end (1 s inside).
+    return T.Trace(
+        ops={0: [E("%while.1", 0.5, 8.5, "mixer"),
+                 E("%fusion.1", 1, 3, "mixer/seqmix"),
+                 E("%fusion.2", 3, 4, "mixer/seqmix/_mlstm_chunk"),
+                 E("%fusion.3", 4, 5, "mixer"),
+                 E("%fusion.4", 5, 6, "ffn"),
+                 E("%fusion.5", 6, 7, "ffn2"),
+                 E("%fusion.6", 7, 8, "ffn/mlp"),
+                 E("%fusion.7", 9, 9.5, ""),
+                 E("%fusion.1", 11, 12, "mixer/seqmix")],
+             1: [E("%fusion.1", 0, 2, "mixer/seqmix"),
+                 E("%fusion.4", 2, 4, "ffn"),
+                 E("%fusion.4", 9, 11, "ffn")]},
+        modules={0: [E("jit_fwd(1)", 0.5, 9.5)], 1: [E("jit_fwd(1)", 0, 11)]},
+        spans=[E("bench.window", 0, 10)])
+
+
+@pytest.mark.parametrize("scope,want", [
+    # chip 0: 2 + 1 (nested) s, the op after the window left out; chip 1: 2
+    ("mixer/seqmix", 2.5),
+    # the loop that holds the body is not a leaf: chip 0 3 + 1, chip 1 2
+    ("mixer", 3.0),
+    # ffn and ffn/mlp, not ffn2: chip 0 1 + 1; chip 1 2 + 1 (window clip)
+    ("ffn", 2.5),
+    ("ffn2", 0.5),
+    ("mixer/seqmix/_mlstm_chunk", 0.5),
+    # a string prefix that is no whole segment matches nothing
+    ("mix", 0.0),
+    ("mixer/seq", 0.0),
+])
+def test_scope_seconds(scope, want):
+    assert _scoped_trace().scope_seconds(scope) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(fwd)/while/body/closed_call/mixer/seqmix/dot_general",
+     "mixer/seqmix"),
+    ("jit(fwd)/while/body/closed_call/while/body/closed_call/mixer/seqmix/"
+     "_mlstm_chunk/mul", "mixer/seqmix/_mlstm_chunk"),
+    ("jit(fwd)/jit(main)/mixer/seqmix/bhgqd,bhkd->bhgqk/dot_general",
+     "mixer/seqmix/bhgqd,bhkd->bhgqk"),
+    ("jit(fwd)/while/body/closed_call/ffn/mul", "ffn"),
+    ("jit(fwd)/while/body/dynamic_slice", ""),
+    ("jit(fwd)/add", ""),
+    ("", ""),
+])
+def test_scope_path_drops_what_jax_adds(op_name, want):
+    assert T.scope_path(op_name) == want
+
+
+@pytest.mark.parametrize("path,scope,want", [
+    ("ffn", "ffn", True), ("ffn/mlp", "ffn", True), ("ffn2", "ffn", False),
+    ("mixer/seqmix", "mixer", True), ("mixer", "mixer/seqmix", False),
+    ("", "ffn", False),
+])
+def test_in_scope_takes_whole_segments(path, scope, want):
+    assert T.in_scope(path, scope) is want
+
+
+HLO = """\
+HloModule jit_fwd, is_scheduled=true
+
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  %mul.1 = f32[8]{0} multiply(f32[8]{0} %param_0, f32[8]{0} %param_0), metadata={op_name="jit(fwd)/while/body/mixer/mul" source_file="x.py" source_line=3}
+  ROOT %add.1 = f32[8]{0} add(f32[8]{0} %mul.1, f32[8]{0} %param_0), metadata={op_name="jit(fwd)/while/body/ffn/add" source_file="x.py" source_line=4}
+}
+
+%fused_computation.1 (param_0.1: f32[8]) -> f32[8] {
+  %param_0.1 = f32[8]{0} parameter(0)
+  ROOT %bitcast.1 = f32[8]{0} bitcast(f32[8]{0} %param_0.1)
+}
+
+ENTRY %main.9 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  %fusion = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(fwd)/while/body/mixer/mul"}
+  %fusion.1 = f32[8]{0} fusion(f32[8]{0} %fusion), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(fwd)/mixer/seqmix/exp"}
+  %copy.2 = f32[8]{0} copy(f32[8]{0} %fusion.1)
+  ROOT %tanh.3 = f32[8]{0} tanh(f32[8]{0} %copy.2), metadata={op_name="jit(fwd)/pool/tanh"}
+}
+"""
+
+
+def test_hlo_scopes_give_a_fusion_its_roots_scope():
+    scopes = T.hlo_scopes(HLO)
+    # the fusion's root is ffn/add, though the fusion itself says mixer/mul
+    assert scopes["%fusion"] == "ffn"
+    # a root with no op name: the fusion's own
+    assert scopes["%fusion.1"] == "mixer/seqmix"
+    assert scopes["%tanh.3"] == "pool"
+    assert "%copy.2" not in scopes and "%x" not in scopes
+
+
+_XSPACE = """
+planes {
+  id: 1
+  name: "/device:TPU:0"
+  lines {
+    id: 1
+    name: "XLA Modules"
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 4000000000000 }
+    events { metadata_id: 2 offset_ps: 5000000000000 duration_ps: 1000000000000 }
+  }
+  lines {
+    id: 2
+    name: "XLA Ops"
+    events { metadata_id: 3 offset_ps: 1000000000000 duration_ps: 2000000000000 }
+    events { metadata_id: 4 offset_ps: 3000000000000 duration_ps: 1000000000000 }
+    events { metadata_id: 3 offset_ps: 5000000000000 duration_ps: 1000000000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "jit_fwd(7)" } }
+  event_metadata { key: 2 value { id: 2 name: "jit_other(8)" } }
+  event_metadata { key: 3 value { id: 3 name: "%fusion = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop, calls=%fused_computation" } }
+  event_metadata { key: 4 value { id: 4 name: "%tanh.3 = f32[8]{0} tanh(f32[8]{0} %copy.2)" } }
+}
+planes {
+  id: 2
+  name: "/host:CPU"
+  lines {
+    id: 1
+    name: "python3"
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "bench.window" } }
+}
+"""
+
+
+def test_from_profile_scopes_ops_by_their_programs_hlo():
+    """The HLO of the program run that holds an operation names its scope,
+    and only that program's: the same instruction name in another program
+    stays unscoped, as does every operation without the HLO."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_text_proto(_XSPACE)
+    tr = T.Trace.from_profile(data, {"jit_fwd": HLO})
+    assert [(T.short(e.name), e.scope) for e in tr.ops[0]] == [
+        ("%fusion", "ffn"), ("%tanh.3", "pool"), ("%fusion", "")]
+    assert tr.scope_seconds("ffn") == pytest.approx(2.0)
+    assert [e.scope for e in T.Trace.from_profile(data).ops[0]] == [""] * 3
+
+
+def test_json_carries_the_scope():
+    tr = _scoped_trace()
+    back = T.Trace.from_json(tr.to_json())
+    assert [e.scope for e in back.ops[0]] == [e.scope for e in tr.ops[0]]
+    assert back.scope_seconds("ffn") == tr.scope_seconds("ffn")
+
+
+def test_recorded_trace_loads_without_scopes():
+    """The recorded trace has three fields an event: every scope empty."""
+    tr = T.Trace.load_json(RECORDED)
+    events = [e for evs in list(tr.ops.values()) + list(tr.modules.values())
+              for e in evs] + tr.spans
+    assert events and all(e.scope == "" for e in events)
+    assert tr.scope_seconds("mixer") == 0.0

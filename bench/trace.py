@@ -14,6 +14,16 @@ kinds of event are kept, with times in seconds on the trace's one clock:
 
 Busy time is the union of the operation intervals, so overlapping events
 count once; the idle share is 1 − busy ÷ window.
+
+Each device operation also keeps its scope path: the names of the
+``jax.named_scope`` blocks open where the program traced it, from the HLO
+``op_name`` metadata with the segments JAX adds itself left out
+(``jit(fwd)/while/body/closed_call/mixer/seqmix/dot_general`` →
+``mixer/seqmix``; see ``scope_path``). A v5e trace's operation events carry
+no op name (their stats are offsets and durations), so it comes from the
+compiled HLO text of the program that ran the operation, handed to
+``from_xplane`` by program name. A fusion takes the scope of its root
+instruction.
 """
 
 from __future__ import annotations
@@ -49,6 +59,7 @@ class Event:
     name: str
     start: float
     end: float
+    scope: str = ""     # a device operation's scope path; "" where none
 
     @property
     def seconds(self) -> float:
@@ -64,10 +75,18 @@ class Trace:
     # -- recording --------------------------------------------------------
 
     @classmethod
-    def from_xplane(cls, path: str) -> "Trace":
+    def from_xplane(cls, path: str,
+                    hlo: Optional[Dict[str, str]] = None) -> "Trace":
+        """The trace in the file at ``path``; ``hlo`` maps a program's name
+        (``jit_fwd``) to its compiled HLO text, which names the scopes of
+        its operations (the operations of other programs have none)."""
         from jax.profiler import ProfileData
 
-        data = ProfileData.from_file(path)
+        return cls.from_profile(ProfileData.from_file(path), hlo)
+
+    @classmethod
+    def from_profile(cls, data,
+                     hlo: Optional[Dict[str, str]] = None) -> "Trace":
         tables = {OPS_LINE: {}, MODULES_LINE: {}}
         spans: List[Event] = []
         for plane in data.planes:
@@ -79,27 +98,36 @@ class Trace:
                 elif chip is None and plane.name.startswith("/host:"):
                     spans.extend(_event(e) for e in line.events
                                  if e.name.startswith(SPAN_PREFIX))
-        return cls(tables[OPS_LINE], tables[MODULES_LINE], spans)
+        ops, modules = tables[OPS_LINE], tables[MODULES_LINE]
+        if hlo:
+            scopes = {prog: hlo_scopes(text) for prog, text in hlo.items()}
+            ops = {c: _scoped_by_program(evs, modules.get(c, []), scopes)
+                   for c, evs in ops.items()}
+        return cls(ops, modules, spans)
 
     @classmethod
-    def from_dir(cls, trace_dir: str) -> "Trace":
+    def from_dir(cls, trace_dir: str,
+                 hlo: Optional[Dict[str, str]] = None) -> "Trace":
         files = sorted(glob.glob(os.path.join(
             trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
         if not files:
             raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-        return cls.from_xplane(files[-1])
+        return cls.from_xplane(files[-1], hlo)
 
     def to_json(self) -> dict:
-        """The trace as JSON, operations by their short names (the format of
-        the recorded trace the tests read)."""
-        enc = lambda evs: [[short(e.name), e.start, e.end] for e in evs]
+        """The trace as JSON, operations by their short names, each event
+        ``[name, start, end, scope]`` (the format of the recorded trace the
+        tests read; one recorded with three fields loads with no scopes)."""
+        enc = lambda evs: [[short(e.name), e.start, e.end, e.scope]
+                           for e in evs]
         table = lambda t: {str(k): enc(v) for k, v in t.items()}
         return {"ops": table(self.ops), "modules": table(self.modules),
                 "spans": enc(self.spans)}
 
     @classmethod
     def from_json(cls, obj: dict) -> "Trace":
-        dec = lambda evs: [Event(n, float(s), float(e)) for n, s, e in evs]
+        dec = lambda evs: [Event(n, float(s), float(e), *scope)
+                           for n, s, e, *scope in evs]
         table = lambda t: {int(k): dec(v) for k, v in t.items()}
         return cls(table(obj["ops"]), table(obj["modules"]), dec(obj["spans"]))
 
@@ -145,6 +173,17 @@ class Trace:
     def _seconds(self, table, match) -> float:
         lo, hi = self.window()
         per = [sum(_clip(e, lo, hi) for e in table[c] if match(e.name))
+               for c in self.chips]
+        return sum(per) / len(per) if per else 0.0
+
+    def scope_seconds(self, scope: str) -> float:
+        """Device seconds of the leaf operations (``leaves``, as ``top_ops``
+        counts them) whose scope path starts with ``scope`` as whole path
+        segments (``ffn`` holds ``ffn/mlp``, not ``ffn2``), inside the
+        window, averaged over the chips."""
+        lo, hi = self.window()
+        per = [sum(_clip(e, lo, hi) for e in leaves(self.ops[c])
+                   if in_scope(e.scope, scope))
                for c in self.chips]
         return sum(per) / len(per) if per else 0.0
 
@@ -211,6 +250,64 @@ def short(name: str) -> str:
     return name.split(" = ", 1)[0]
 
 
+# Segments that JAX itself puts into an op name: transformations with their
+# argument (``jit(fwd)``) and the loops and calls of ``lax.scan``
+# (``while/body``, ``while/cond``, ``closed_call``). What is left of the
+# path are the program's own scopes.
+_JAX_SEGMENT = re.compile(r"^(?:[A-Za-z_]+\(.*\)|while|body|cond|closed_call)$")
+_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?(%[^\s=]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=(%[^\s,}]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[^\s(]+)\s*\(.*\{\s*$")
+
+
+def scope_path(op_name: str) -> str:
+    """The scopes of an HLO ``op_name``: its last segment (the primitive)
+    and the segments JAX adds itself left out."""
+    segments = op_name.split("/")[:-1]
+    return "/".join(s for s in segments if not _JAX_SEGMENT.match(s))
+
+
+def in_scope(path: str, scope: str) -> bool:
+    """Whether the scope path ``path`` lies under ``scope``, as whole path
+    segments."""
+    return path == scope or path.startswith(scope + "/")
+
+
+def hlo_scopes(text: str) -> Dict[str, str]:
+    """Instruction name (``%fusion.3``) → scope path, from a compiled
+    program's HLO text. A fusion takes the scope of its fused computation's
+    root instruction, or its own where that root has no op name."""
+    own: Dict[str, str] = {}        # instruction → its op_name, if any
+    calls: Dict[str, str] = {}      # fusion → the computation it fuses
+    roots: Dict[str, str] = {}      # computation → its root instruction
+    computation = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        root, name, rest = m.groups()
+        if root and computation:
+            roots[computation] = name
+        op = _OP_NAME.search(rest)
+        if op:
+            own[name] = op.group(1)
+        called = _CALLS.search(rest)
+        if called and " fusion(" in rest:
+            calls[name] = called.group(1)
+
+    def op_name(name: str) -> str:
+        root = roots.get(calls.get(name))
+        return (root and op_name(root)) or own.get(name, "")
+
+    names = set(own) | set(calls)
+    return {n: scope_path(op_name(n)) for n in names if op_name(n)}
+
+
 def merged(intervals: List[Interval], lo: float, hi: float) -> List[Interval]:
     """The union of the intervals, clipped to [lo, hi], as disjoint sorted
     intervals."""
@@ -251,3 +348,17 @@ def _chip_index(plane_name: str) -> Optional[int]:
 def _event(e) -> Event:
     start = e.start_ns * 1e-9
     return Event(e.name, start, start + e.duration_ns * 1e-9)
+
+
+def _scoped_by_program(ops: List[Event], programs: List[Event],
+                       scopes: Dict[str, Dict[str, str]]) -> List[Event]:
+    """The operations, each with the scope that the HLO of the program run
+    holding it names."""
+    programs = sorted(programs, key=lambda m: m.start)
+    starts = [m.start for m in programs]
+    out = []
+    for e in ops:
+        table = scopes.get(_program_of(e, programs, starts))
+        out.append(Event(e.name, e.start, e.end, table.get(short(e.name), ""))
+                   if table else e)
+    return out
